@@ -1,9 +1,9 @@
 """Replica lifecycle: warm restarts, readiness gating, graceful drain,
 and elastic pool sizing (PR 14).
 
-BENCH_r05 records 130-500 s of `*_compile_plus_run_s` per program: a
-restarted replica that recompiles every jit shape from scratch is blind
-for MINUTES — fatal for rolling a fleet under the north-star traffic.
+Each fused program takes minutes to compile cold: a restarted replica
+that recompiles every jit shape from scratch is blind for MINUTES —
+fatal for rolling a fleet under the north-star traffic.
 This module makes restarts cheap and visible:
 
   SHAPE MANIFEST   ShapeManifest persists the engine's per-program
@@ -55,6 +55,7 @@ counters "lifecycle_warmed_shapes", "lifecycle_warm_skipped",
 """
 
 import json
+import os
 import threading
 import time
 
@@ -77,23 +78,23 @@ def _remaining(deadline):
 
 
 def configure_compilation_cache(cache_dir=None):
-    """Best-effort: point JAX's persistent compilation cache at
-    `cache_dir` (or the repo default via tpu.enable_compile_cache when
-    None). Returns True when the cache was configured, False when jax is
-    unavailable or refused — warm boot proceeds either way; the cache
-    only changes how much the first cold shape costs."""
+    """Best-effort: turn on JAX's persistent compilation cache. A
+    JAX_COMPILATION_CACHE_DIR in the environment always wins (JAX reads
+    it itself); otherwise `cache_dir`, or the checkout default of
+    tpu.enable_compile_cache when None. Returns True when the cache was
+    configured, False when jax is unavailable or refused — warm boot
+    proceeds either way; the cache only changes how much the first cold
+    shape costs."""
     try:
-        if cache_dir is None:
-            from ..tpu import enable_compile_cache
+        from ..tpu import enable_compile_cache
 
-            enable_compile_cache()
-        else:
+        enable_compile_cache()
+        if cache_dir is not None and not os.environ.get(
+            "JAX_COMPILATION_CACHE_DIR"
+        ):
             import jax
 
             jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 5.0
-            )
         return True
     except Exception:
         metrics.count("lifecycle_cache_config_errors")

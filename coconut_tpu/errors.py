@@ -125,7 +125,7 @@ class GeneralError(CoconutError):
 
 class TransientBackendError(CoconutError):
     """A backend dispatch or readback failure that is expected to succeed
-    on re-attempt (device preemption, tunnel RPC hiccup, transient transfer
+    on re-attempt (device preemption, host RPC hiccup, transient transfer
     failure). The stream supervision layer (stream.verify_stream +
     retry.RetryPolicy) retries these with bounded backoff and then falls
     back to a designated backend; any other exception class is treated as

@@ -187,7 +187,7 @@ class FaultyBackend:
 
       raise_every=N  — every Nth dispatch (indices N-1, 2N-1, ...) raises
                        `error` at dispatch time, before the inner backend
-                       runs (a device/tunnel failure on submit);
+                       runs (a device/transport failure on submit);
       raise_on       — explicit dispatch indices that raise at dispatch;
       flip_on        — dispatch indices whose verdicts are negated
                        (elementwise for per-credential lists, the single

@@ -2,7 +2,7 @@
 
 SURVEY.md §7 stage 1's Python-visible face: the same `CurveBackend` seam the
 JAX backend implements, routed through the batch C ABI of `libccbls.so`.
-The native library is the framework's CPU baseline (BASELINE.md) and the
+The native library is the framework's CPU baseline and the
 const-time issuance path (reference const-time MSM call sites
 signature.rs:157,424-428): `ct=True` selects the masked-lookup schedule,
 which accumulates through the COMPLETE Renes-Costello-Batina projective
@@ -14,10 +14,13 @@ Wire codec (must match ccbls.cpp): Fp = 48B LE canonical; affine G1 = x||y
 (96B), G2 = x.c0||x.c1||y.c0||y.c1 (192B); infinity = all-zero bytes
 (0^3+4 != 0 so the encoding is unambiguous); scalars = 32B LE canonical Fr.
 
-Build on demand: `make -C native` (g++); `CCBLS_SO` overrides the path.
+Built from the committed source on every first load (`make -C native
+libccbls.so`: make's mtime rule rebuilds a library older than ccbls.cpp
+and is a no-op otherwise); `CCBLS_SO` loads a prebuilt library as is.
 """
 
 import ctypes
+import fcntl
 import os
 import subprocess
 
@@ -31,19 +34,24 @@ _lib = None
 
 
 def _build():
-    subprocess.run(
-        ["make", "-C", _NATIVE_DIR, "libccbls.so"],
-        check=True,
-        capture_output=True,
-    )
+    # serialized across processes (pytest-xdist workers load at once):
+    # the lock on the committed Makefile keeps a second process from
+    # loading a library the first is still writing
+    with open(os.path.join(_NATIVE_DIR, "Makefile")) as lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "libccbls.so"],
+            check=True,
+            capture_output=True,
+        )
 
 
-def load(build_if_missing=True):
-    """Load (building if needed) and selftest the native library."""
+def load():
+    """Build (when stale) and load, then selftest, the native library."""
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO_PATH) and build_if_missing:
+    if "CCBLS_SO" not in os.environ:
         _build()
     lib = ctypes.CDLL(_SO_PATH)
     lib.cc_selftest.restype = ctypes.c_int
@@ -227,7 +235,7 @@ def hash_to_g2(msg, dst=None):
 # signature.rs:513,521: large-t Verkey.aggregate and any big-MSM workload) --
 
 # Below this size the windowed row schedule beats the bucket combine; the
-# crossover was measured on this box (BASELINE.md "Pippenger crossover").
+# crossover was measured on the build box.
 PIPPENGER_MIN = 96
 
 

@@ -31,7 +31,7 @@ ExecutorHealth — a circuit breaker per executor::
     metrics.py.
 
 Watchdog — deadline-checks in-flight dispatches. PR-2's retry ladder only
-fires when a dispatch RETURNS; a wedged device (or a deadlocked tunnel
+fires when a dispatch RETURNS; a wedged device (or a deadlocked host
 RPC) never returns, so the watchdog tracks every dispatch from launch and
 `expire()`s the ones that outlive their budget: ``k × EMA`` of that
 executor's observed dispatch-to-settle time, clamped to

@@ -21,8 +21,7 @@ turns one 255-bit scalar on one base into two <= 128-bit scalars on two
 bases. For the Horner-style distinct-base MSM (curve.msm_distinct_signed:
 5 doublings per window) this halves the doubling chain (52 -> 27 windows)
 while keeping the add count — the win the grouped/comb schedules cannot
-get from GLV (they have no doublings; VERDICT r3 item 3 analysis in
-BASELINE.md). phi itself costs one host-side Fp mul per base (beta * x).
+get from GLV (they have no doublings). phi itself costs one host-side Fp mul per base (beta * x).
 
 Reference workload this accelerates: the issuance MSMs
 (signature.rs:396-428) and the show prover's sigma re-randomization

@@ -30,6 +30,7 @@ Enabled automatically when the default JAX backend is a TPU (CPU tests
 keep the pure-XLA path), or forced via COCONUT_FP_PALLAS=1/0.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -283,10 +284,16 @@ def _mul_kernel(a_ref, b_ref, band_ref, np_ref, p_ref, out_ref):
     out_ref[:] = _norm(hi, 3)
 
 
+@functools.partial(jax.jit, static_argnames=("nblocks", "interpret"))
 def _mul_flat(at, bt, nblocks, interpret=False):
     """at, bt: f32 [52, nblocks*TN] transposed operands -> [52, n] product.
     interpret=True runs the kernel through the Pallas interpreter (any
-    backend) — the CPU differential-test hook for this TPU-only path."""
+    backend) — the CPU differential-test hook for this TPU-only path.
+
+    Jitted so that a fused program traces the kernel body once per lane
+    width: `pallas_call` re-traces its kernel on every call, and the
+    per-credential verifier makes ~500 multiplies (~0.4 s of tracing
+    each); nested jit caches the trace and lowers one shared function."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -343,10 +350,9 @@ def enabled():
     if _ENABLED is None:
         flag = os.environ.get("COCONUT_FP_PALLAS", "auto")
         if flag == "auto":
-            try:
-                _ENABLED = jax.default_backend() == "tpu"
-            except Exception:  # pragma: no cover
-                _ENABLED = False
+            from . import on_tpu
+
+            _ENABLED = on_tpu()
         else:
             _ENABLED = flag == "1"
     return _ENABLED

@@ -24,10 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import backend as bk
 from . import curve as cv
@@ -53,21 +50,14 @@ def require_axes(mesh, *axes):
 
 
 def _shard_map(local, mesh, in_specs, out_specs):
-    """shard_map with the check_vma/check_rep spelling fallback (the
-    scans initialize carries from replicated constants that become
-    mesh-varying inside the loop — sound, since every sharded program's
-    outputs are asserted bit-identical to the spec path, but rejected by
-    the static vma check; older jax spells the kwarg check_rep)."""
-    try:
-        return shard_map(
-            local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # pragma: no cover - jax < 0.4.35 spelling
-        return shard_map(
-            local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """shard_map with check_vma=False: the scans initialize carries from
+    replicated constants that become mesh-varying inside the loop — sound,
+    since every sharded program's outputs are asserted bit-identical to
+    the spec path, but rejected by the static vma check."""
+    return shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def make_sharded_verify(mesh, sig_is_g1, batch_axis="dp", msm_axis="tp"):

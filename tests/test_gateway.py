@@ -43,6 +43,7 @@ from coconut_tpu.errors import (
     EpochRetiredError,
     EpochUnknownError,
     GeneralError,
+    PSError,
     QuorumUnreachableError,
     ServiceBrownoutError,
     ServiceClosedError,
@@ -53,6 +54,8 @@ from coconut_tpu.errors import (
     TenantQuotaError,
     TenantRateLimitError,
     TransientBackendError,
+    UnequalNoOfBasesExponents,
+    UnsupportedNoOfMessages,
     error_from_wire,
 )
 from coconut_tpu.keygen import trusted_party_SSS_keygen
@@ -398,6 +401,10 @@ def test_error_codes_stable_and_unique():
         EpochRetiredError: "epoch_retired",
         # PR 17: the replicated nullifier set's terminal rejection
         DoubleSpendError: "double_spend",
+        # PR 20: the protocol-shape errors cross the wire as themselves
+        UnsupportedNoOfMessages: "unsupported_messages",
+        UnequalNoOfBasesExponents: "unequal_bases_exponents",
+        PSError: "ps_error",
     }
     for cls, code in expected.items():
         assert cls.code == code

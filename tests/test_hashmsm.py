@@ -1,6 +1,7 @@
 """Device hash-to-curve + bucketed MSM suite (PR 18).
 
-Two kernels close the last two PROFILE_r05 walls, and both are pure
+Two kernels move the prepare hash and the show-prove sigma MSM onto
+device schedules, and both are pure
 re-schedules of already-proven math — so every test here is a BIT
 parity test against an independent oracle, never a statistical one:
 

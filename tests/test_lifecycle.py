@@ -769,3 +769,40 @@ def test_rolling_restart_drill_drops_nothing(world, tmp_path):
     assert all(
         s == gossip.UP for s in router.directory.states().values()
     )
+
+
+# --- compile-cache placement (ISSUE 21) ------------------------------------
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore JAX's cache-dir setting after a test moves it."""
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    yield jax
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("env_dir", [None, "/tmp/coconut-env-cache"])
+def test_compile_cache_dir_from_env_else_checkout(
+    cache_dir_config, monkeypatch, env_dir
+):
+    """JAX_COMPILATION_CACHE_DIR wins wherever it is set — neither the
+    shared helper nor the lifecycle boot hook (even handed its own
+    directory) overrides it; unset, the cache is the checkout's
+    .jax_cache."""
+    import coconut_tpu.tpu as ctpu
+
+    jax = cache_dir_config
+    jax.config.update("jax_compilation_cache_dir", env_dir)
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    want = env_dir or ctpu.DEFAULT_CACHE_DIR
+    assert ctpu.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert lc_mod.configure_compilation_cache("/tmp/coconut-boot-cache")
+    want = env_dir or "/tmp/coconut-boot-cache"
+    assert jax.config.jax_compilation_cache_dir == want

@@ -16,7 +16,7 @@ from types import SimpleNamespace
 import pytest
 
 from coconut_tpu import metrics
-from coconut_tpu.faults import DeadLetterLog, FaultyBackend
+from coconut_tpu.faults import DEAD_LETTER_SCHEMA, DeadLetterLog, FaultyBackend
 from coconut_tpu.obs import export as oexport
 from coconut_tpu.obs import flight as oflight
 from coconut_tpu.obs import trace as otrace
@@ -463,7 +463,8 @@ def test_serve_request_span_tree_retry_and_bisection(_traced, clock, tmp_path):
     assert spans["device"].attrs["device"] == "0"
     # dead-letter line joins back on the victim's trace_id
     (rec,) = DeadLetterLog.read(dlq)
-    assert rec["trace_id"] == victim.trace_id and rec["schema"] == 3
+    assert rec["trace_id"] == victim.trace_id
+    assert rec["schema"] == DEAD_LETTER_SCHEMA
     assert rec["program"] == "verify"
     # flight record rides next to the dead-letter log with the full tree
     (flight,) = oflight.read(dlq)
