@@ -533,20 +533,19 @@ else
   echo "scenarios bench smoke: skipped (BENCH_SCENARIOS=0)"
 fi
 
-echo "== obs lane (request-scoped tracing / Perfetto export / flight recorder) =="
+echo "== obs lane (request-scoped tracing / profiler bridge / flight recorder) =="
 python -m pytest tests/test_obs.py -m obs -q
 # end-to-end acceptance smoke on the REAL service (CPU, stub backend):
 # one injected dispatch fault + one forged credential, tracing enabled.
 # The forged request's span tree must show admission -> coalesce ->
-# dispatch -> retry -> bisection -> dead-letter, its trace_id must appear
-# in the dead-letter JSONL line AND the flight record, and the Chrome
-# trace export must pass probe_trace's structural validation.
+# dispatch -> retry -> bisection -> dead-letter, and its trace_id must
+# appear in the dead-letter JSONL line AND the flight record.
 OBS_DIR=$(mktemp -d)
-OBS_DLQ="$OBS_DIR/dead.jsonl" OBS_TRACE="$OBS_DIR/trace.json" python - <<'EOF'
+OBS_DLQ="$OBS_DIR/dead.jsonl" python - <<'EOF'
 import os
 from types import SimpleNamespace
 from coconut_tpu.faults import DeadLetterLog, FaultyBackend
-from coconut_tpu.obs import export, flight
+from coconut_tpu.obs import flight
 from coconut_tpu.obs import trace as otrace
 from coconut_tpu.retry import RetryPolicy
 from coconut_tpu.serve.service import CredentialService
@@ -580,12 +579,9 @@ events = {e["name"] for s in tree for e in s.events}
 assert {"retry", "attempt_failed", "split", "dead_letter"} <= events, events
 (fl,) = flight.read(dlq)
 assert fl["trace_id"] == futs[2].trace_id and fl["reason"] == "dead_letter"
-n = export.export_chrome(os.environ["OBS_TRACE"])
-assert n > 0
-print("obs smoke: ok (%d trace events, culprit trace %s)"
-      % (n, rec["trace_id"]))
+print("obs smoke: ok (%d spans in the culprit's tree, trace %s)"
+      % (len(tree), rec["trace_id"]))
 EOF
-JAX_PLATFORMS=cpu python probes/probe_trace.py "$OBS_DIR/trace.json"
 test -f "$OBS_DIR/dead.jsonl.flight.jsonl"
 
 echo "== encode-pipeline lane (prefetch worker / static cache / raw wire) =="

@@ -957,7 +957,7 @@ class ExecutionEngine:
             executor.label, seq, requests, span=bspan, now=self.clock()
         )
         with otrace.use(bspan), metrics.timer(executor.busy_timer):
-            with otrace.span("coalesce"):
+            with otrace.span("coalesce", ns=prog.metric_ns):
                 payload_a, payload_b = prog.assemble(requests, bspan)
             metrics.observe(
                 "%s_batch_wait_s" % prog.metric_ns,
@@ -979,6 +979,7 @@ class ExecutionEngine:
             permanent = None
             with otrace.span(
                 "dispatch",
+                ns=prog.metric_ns,
                 backend=prog.backend_label(),
                 device=executor.label,
             ):
@@ -1047,7 +1048,9 @@ class ExecutionEngine:
             executor = self._executors[0]
         with otrace.use(bspan), metrics.timer(executor.busy_timer):
             try:
-                with otrace.span("device", device=executor.label):
+                with otrace.span(
+                    "device", ns=prog.metric_ns, device=executor.label
+                ):
                     result = finalize()
             except Exception as e:
                 self._watchdog.end(

@@ -65,7 +65,7 @@ def _group_by_epoch(epochs):
 def _demux_results(requests, results, metric_ns, clock):
     """Resolve each request's future with its own lane's output (pad
     lanes beyond len(requests) are discarded)."""
-    with otrace.span("demux", n=len(requests)):
+    with otrace.span("demux", ns=metric_ns, n=len(requests)):
         now = clock()
         for req, out in zip(requests, results):
             metrics.observe("%s_latency_s" % metric_ns, now - req.t_submit)
@@ -463,7 +463,7 @@ class ShowVerifyProgram(Program):
         null_epochs = aux[4] if len(aux) > 4 else None
         null_domains = aux[5] if len(aux) > 5 else None
         guard = self.nullifiers
-        with otrace.span("demux", n=len(requests)):
+        with otrace.span("demux", ns=self.metric_ns, n=len(requests)):
             now = self.engine.clock()
             n = len(requests)
             bits = [bool(b) for b in list(result)[:n]]

@@ -173,10 +173,13 @@ class SigningAuthority:
             self._cond.notify_all()
 
     def join(self, timeout=None):
-        if self._thread is None:
+        # abandon() may drop _thread at any time: read it once
+        with self._cond:
+            thread = self._thread
+        if thread is None:
             return True
-        self._thread.join(timeout)
-        return not self._thread.is_alive()
+        thread.join(timeout)
+        return not thread.is_alive()
 
     def has_worker(self):
         with self._cond:

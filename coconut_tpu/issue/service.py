@@ -615,7 +615,13 @@ class MintProgram(Program):
             return
         t0 = self.engine.clock()
         try:
-            with metrics.timer(auth.busy_timer):
+            with metrics.timer(auth.busy_timer), otrace.span(
+                "sign",
+                parent=fanout.bspan,
+                ns="issue",
+                fanout=fanout.fid,
+                authority=auth.label,
+            ):
                 partials = auth.sign(
                     fanout.sig_reqs, self.params, keyset=fanout.keyset
                 )
@@ -664,7 +670,9 @@ class MintProgram(Program):
         messages_list = [fanout.messages_list[idx] for idx in indices]
         minter = self._minter_for(fanout.keyset)
         try:
-            with otrace.use(fanout.bspan):
+            with otrace.use(fanout.bspan), otrace.span(
+                "mint_round", ns="issue", fanout=fanout.fid, n=len(indices)
+            ):
                 with otrace.span("unblind", n=len(indices), t=len(subset)):
                     sig_rows = minter.unblind(blind_rows, sks)
                 with otrace.span("aggregate", subset=list(subset)):
@@ -754,16 +762,20 @@ class MintProgram(Program):
         gate by construction."""
         now = self.engine.clock()
         epoch = fanout.keyset.epoch if fanout.keyset is not None else None
-        for idx in indices:
-            r = fanout.requests[idx]
-            cred = creds_by_idx[idx]
-            if epoch is not None:
-                # the credential's mint epoch rides with it (and over the
-                # wire): verify resolves the aggregated verkey by epoch
-                cred.epoch = epoch
-            metrics.observe("issue_latency_s", now - r.t_submit)
-            r.span.end(verdict=True)
-            r.future.set_result(cred)
+        with otrace.span(
+            "release", parent=fanout.bspan, ns="issue", n=len(indices)
+        ):
+            for idx in indices:
+                r = fanout.requests[idx]
+                cred = creds_by_idx[idx]
+                if epoch is not None:
+                    # the credential's mint epoch rides with it (and over
+                    # the wire): verify resolves the aggregated verkey by
+                    # epoch
+                    cred.epoch = epoch
+                metrics.observe("issue_latency_s", now - r.t_submit)
+                r.span.end(verdict=True)
+                r.future.set_result(cred)
         metrics.count("issue_minted", len(indices))
 
     def _fail_requests(self, fanout, indices, exc):

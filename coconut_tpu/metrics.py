@@ -202,12 +202,14 @@ coalesce / encode / device / demux breakdown that separates "slow device"
 from "slow batcher" — via `register_provider`, so this module never
 imports obs (providers are injected, not imported).
 
-Device-side profiling is separate: the hot kernels in tpu/backend.py carry
-`jax.named_scope` annotations (comb_msm, grouped_tables /
-grouped_gather_fold / grouped_horner, miller_two_pairs / grouped_miller,
-affine_norm, final_exp) and `BENCH_PROFILE=1 python bench.py` writes a
-`jax.profiler` trace broken down by those scopes; host-side phases are
-what these timers capture.
+While a `jax.profiler` session collects, obs.trace bridges every stage
+span into the profiler trace as a "coconut/<ns>.<span>" annotation and
+observes its duration in the "bridge_<ns>_<span>_s" histogram (e.g.
+"bridge_issue_sign_s", "bridge_stream_encode_s") — the same stages a
+host-side reader sees without parsing the trace. Device time itself
+comes only from the profiler trace: `python3 -m benchmark.run ...
+--trace 1` reduces it per program and per top-level op
+(benchmark/trace.py); host-side phases are what these timers capture.
 """
 
 import threading

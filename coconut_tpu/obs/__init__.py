@@ -2,9 +2,10 @@
 flight recorder.
 
   trace.py   Span/Tracer — contextvar propagation, injectable clock,
-             bounded ring buffer, zero-cost no-op path when disabled
-             (COCONUT_TRACE=0, the default)
-  export.py  JSONL span records + Chrome-trace/Perfetto JSON
+             bounded ring buffer, stage spans bridged into a collecting
+             jax.profiler session, zero-cost no-op path when both are
+             off (COCONUT_TRACE=0, the default)
+  export.py  JSONL span records
   flight.py  on dead-letter / checkpoint quarantine, dump the failing
              request's span tree + the recent-span tail to a JSONL next
              to the triggering artifact

@@ -7,39 +7,64 @@ every streamed batch) gets a trace — a tree of timed spans — so a
 credential that survives a retry->fallback->bisection ladder before
 dead-lettering leaves a joinable record of exactly that path.
 
-Design constraints, in order:
+Two sinks, two independent switches:
 
-  - ZERO-COST WHEN OFF (the default): every entry point first checks the
-    module-level `_tracer is None` and returns the shared `NOOP` span —
-    no Span is ever allocated, no lock taken, no clock read. The serve
-    and bench hot paths run with tracing off unless `COCONUT_TRACE=1`.
-  - BOUNDED MEMORY: finished spans land in a ring buffer
-    (`COCONUT_TRACE_RING`, default 4096) — a million-request run retains
-    the most recent few thousand spans, kilobytes not gigabytes. The
-    flight recorder (obs/flight.py) exists precisely because the ring
-    forgets: it dumps a request's tree at the moment of failure.
-  - INJECTABLE CLOCK: `enable(clock=...)` takes any monotonic callable,
-    so span durations are testable exactly with a fake clock and zero
-    real sleeps (the same discipline serve/queue.py uses).
-  - CROSS-THREAD TREES: propagation inside one thread rides a
-    contextvar (`span()` activates, nested spans parent automatically);
-    across threads — a request admitted on a client thread, batched on
-    the supervisor — the span object itself is handed over and re-entered
-    with `use()`. Spans are safe to start/annotate/end from any thread.
+  - THE RING (`COCONUT_TRACE=1` or `enable()`): finished spans land in a
+    bounded ring buffer (`COCONUT_TRACE_RING`, default 4096) on an
+    injectable monotonic clock, so span durations are testable exactly
+    with a fake clock and zero real sleeps. The flight recorder
+    (obs/flight.py) dumps a request's tree from it at the moment of
+    failure.
+  - THE PROFILER BRIDGE (on whenever a `jax.profiler` session collects,
+    i.e. `TraceAnnotation.is_enabled()`): every `with span(...)` stage
+    span — entered and left on one thread — also opens a
+    `jax.profiler.TraceAnnotation` named "coconut/<ns>.<span>", on the
+    profiler's clock beside the device's ops, and observes its duration
+    in the "bridge_<ns>_<span>_s" histogram. `<ns>` is the program's
+    metric namespace ("prep", "issue", "serve", ...; "stream" for
+    verify_stream), passed at the site or inherited from the enclosing
+    bridged span, so the backend's shared "encode" reads "stream.encode"
+    under the verify stream and "issue.encode" under a sign.
+    `start_span` roots ended on another thread (request, queue_wait,
+    batch, issue_batch, stream_batch) stay in the ring only.
+
+ZERO-COST WHEN OFF (the default): with both switches off every entry
+point tests the module-level `_tracer is None` (and, for `span()`, the
+profiler's `is_enabled()`) and returns the shared `NOOP` span — no Span
+or annotation is allocated, no lock taken, no clock read.
+
+CROSS-THREAD TREES: propagation inside one thread rides a contextvar
+(`span()` activates, nested spans parent automatically); across threads
+— a request admitted on a client thread, batched on the supervisor —
+the span object itself is handed over and re-entered with `use()`.
+Spans are safe to start/annotate/end from any thread.
 
 Span taxonomy (README "Observability" for the glossary):
 
   per-request trace:  request            admission -> verdict (root)
                         queue_wait       admission -> popped into a batch
-  per-batch trace:    batch | stream_batch   (root; links member traces
-                                              via the members attr, and
-                                              each request span carries
-                                              batch_trace back)
+  per-batch trace:    batch | stream_batch | issue_batch
+                                         (root; links member traces via
+                                          the members attr, and each
+                                          request span carries
+                                          batch_trace back)
                         coalesce         pad/assemble the device batch
                         dispatch         host encode + device dispatch
                         device           blocking wait on the device
                         demux            verdict bits -> futures
                         bisect           grouped-failure culprit isolation
+                        mint_round       unblind + aggregate + verify of
+                                         one fan-out (issuance)
+                          unblind / aggregate / verify
+                        release          minted credentials -> futures,
+                                         their done callbacks included
+  authority thread:   sign               one authority's blind-sign of
+                                         one fan-out
+  supervisor thread:  batch_wait         queued requests waiting for a
+                                         full batch or a deadline
+                      backpressure       a backlog held by the ready gate
+  backend (shared):   encode             host encode before a dispatch
+                      decode             readback + host decode of an MSM
 
   events (timestamped points on a span): retry / attempt_failed /
   fallback (retry.py ladder), split (each bisection halving),
@@ -51,7 +76,7 @@ Span taxonomy (README "Observability" for the glossary):
   dead-lettered request's span tree names the device that rejected it
   and which side of the adaptive routing policy its batch took.
 
-`metrics.snapshot()` gains a "trace_stages" section while tracing is
+`metrics.snapshot()` gains a "trace_stages" section while the ring is
 enabled (per-span-name count/total/mean — the queue-wait vs coalesce vs
 encode vs device vs demux breakdown), via metrics' provider hook so the
 two modules stay decoupled.
@@ -60,6 +85,7 @@ two modules stay decoupled.
 import contextvars
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -357,10 +383,77 @@ def start_span(name, parent=None, root=False, **attrs):
     return t.start(name, parent=parent, attrs=attrs or None)
 
 
-def span(name, parent=None, root=False, **attrs):
+def span(name, parent=None, root=False, ns=None, **attrs):
     """`with span("dispatch"): ...` — start + activate + end-on-exit.
-    The no-op singleton when tracing is disabled."""
-    return start_span(name, parent=parent, root=root, **attrs)
+    While a profiler session collects, the span is also bridged into it
+    as "coconut/<ns>.<name>" (`ns` defaults to the enclosing bridged
+    span's). The no-op singleton when the ring and the profiler are both
+    off."""
+    inner = start_span(name, parent=parent, root=root, **attrs)
+    if not _collecting():
+        return inner
+    return _Bridged(inner, name, ns, attrs)
+
+
+#: the profiler's `TraceAnnotation.is_enabled`, resolved once jax is
+#: imported (no jax, no profiler session)
+_is_enabled = None
+#: the namespace of the innermost bridged span on this context
+_ns = contextvars.ContextVar("coconut_trace_ns", default=None)
+PROFILER_PREFIX = "coconut/"
+
+
+def _collecting():
+    """True while a jax.profiler session collects host annotations."""
+    global _is_enabled
+    if _is_enabled is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _is_enabled = TraceAnnotation.is_enabled
+    return _is_enabled()
+
+
+class _Bridged:
+    """A `span()` opened while a profiler session collects: the ring span
+    (or NOOP) plus a `jax.profiler.TraceAnnotation` named
+    "coconut/<ns>.<name>", entered and left on the same thread. Its
+    duration also lands in the "trace_<ns>_<name>_s" histogram, so a
+    host-side reader sees the same stages without parsing the trace."""
+
+    __slots__ = ("_inner", "_ns", "_label", "_attrs", "_ann", "_token", "_t0")
+
+    def __init__(self, inner, name, ns, attrs):
+        self._inner = inner
+        self._ns = ns if ns is not None else _ns.get()
+        self._label = "%s.%s" % (self._ns, name) if self._ns else name
+        self._attrs = attrs
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._token = _ns.set(self._ns)
+        self._ann = TraceAnnotation(
+            PROFILER_PREFIX + self._label, **self._attrs
+        )
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self._inner.__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            self._inner.__exit__(exc_type, exc, tb)
+        finally:
+            dt = time.perf_counter() - self._t0
+            self._ann.__exit__(exc_type, exc, tb)
+            _ns.reset(self._token)
+            from .. import metrics
+
+            metrics.observe(
+                "bridge_%s_s" % self._label.replace(".", "_"), dt
+            )
+        return False
 
 
 class _Use:

@@ -104,31 +104,52 @@ class Batcher:
         queue so the wait here re-checks. A CLOSED queue bypasses the
         gate: at drain the backlog must flush (the placer's forced-spill
         placement still settles it) rather than park forever behind a
-        pool that lost its capacity."""
+        pool that lost its capacity.
+
+        While queued requests wait, a "batch_wait" span is open (a
+        "backpressure" span while the ready gate holds them), closed at
+        the flush; a wait on an empty queue opens none."""
         q = self.queue
+        waiting, wait_span = None, otrace.NOOP
         with q.cond:
-            while True:
-                if ready is not None and not q.closed and not ready():
-                    wait_s = _POLL_CAP_S
-                else:
-                    flush, wait_s = self._ready_locked()
-                    if flush:
-                        batch = q._pop_locked(self.max_batch)
-                        metrics.count("%s_batches" % self.metric_ns)
-                        metrics.count(
-                            "%s_batched_requests" % self.metric_ns, len(batch)
-                        )
-                        for req in batch:
-                            # queue_wait ends the moment the request is IN
-                            # a coalesced batch — its dur is the admission->
-                            # flush latency the per-stage breakdown reports
-                            req.queue_span.end(coalesced_with=len(batch))
-                        return batch
-                if q.closed and q._depth_locked() == 0:
-                    return None
-                if not block:
-                    return None
-                q.cond.wait(wait_s)
+            try:
+                while True:
+                    held = ready is not None and not q.closed and not ready()
+                    if held:
+                        wait_s = _POLL_CAP_S
+                    else:
+                        flush, wait_s = self._ready_locked()
+                        if flush:
+                            return self._pop_locked()
+                    if q.closed and q._depth_locked() == 0:
+                        return None
+                    if not block:
+                        return None
+                    kind = None
+                    if q._depth_locked():
+                        kind = "backpressure" if held else "batch_wait"
+                    if kind != waiting:
+                        wait_span.__exit__(None, None, None)
+                        wait_span = otrace.NOOP
+                        if kind is not None:
+                            wait_span = otrace.span(kind, ns=self.metric_ns)
+                            wait_span.__enter__()
+                        waiting = kind
+                    q.cond.wait(wait_s)
+            finally:
+                wait_span.__exit__(None, None, None)
+
+    def _pop_locked(self):
+        q = self.queue
+        batch = q._pop_locked(self.max_batch)
+        metrics.count("%s_batches" % self.metric_ns)
+        metrics.count("%s_batched_requests" % self.metric_ns, len(batch))
+        for req in batch:
+            # queue_wait ends the moment the request is IN a coalesced
+            # batch — its dur is the admission->flush latency the
+            # per-stage breakdown reports
+            req.queue_span.end(coalesced_with=len(batch))
+        return batch
 
 
 def pad_batch(requests, max_batch):
@@ -158,7 +179,7 @@ def demux(requests, bits, clock=time.monotonic):
     per-request latency histogram and verdict counters. Each request's
     root span ends here, stamped with its verdict — the trace covers
     admission through verdict delivery."""
-    with otrace.span("demux", n=len(requests)):
+    with otrace.span("demux", ns="serve", n=len(requests)):
         now = clock()
         n_valid = 0
         for req, bit in zip(requests, bits):

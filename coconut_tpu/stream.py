@@ -718,7 +718,9 @@ def verify_stream(
             "stream_batch", root=True, batch=i, n=len(sigs)
         )
         with otrace.use(bspan):
-            with otrace.span("dispatch", backend=type(backend).__name__):
+            with otrace.span(
+                "dispatch", ns="stream", backend=type(backend).__name__
+            ):
                 try:
                     box[0] = dispatch(sigs, msgs, vk, params)
                 except policy.retryable as e:
@@ -751,7 +753,7 @@ def verify_stream(
     def settle(idx, finalize, n, sigs, msgs, attempts, bspan):
         with otrace.use(bspan):
             try:
-                with otrace.span("device"):
+                with otrace.span("device", ns="stream"):
                     result = finalize()
             except BaseException as e:
                 bspan.end(error=type(e).__name__)

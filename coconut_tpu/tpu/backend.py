@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..backend import CurveBackend
+from ..obs import trace as otrace
 from ..ops.curve import g1 as _sg1, g2 as _sg2
 from ..ops.fields import R
 from . import curve as cv
@@ -1208,49 +1209,50 @@ class JaxBackend(CurveBackend):
         default; the bucketed schedule passes _bucket_window's choice);
         nwin follows as ceil(bits/window) + 1 carry window over the
         128-bit GLV halves or the full 255-bit Fr."""
-        B = len(points_batch)
-        k = len(points_batch[0])
-        if any(len(row) != k for row in points_batch):
-            raise ValueError("ragged distinct-MSM batch")
-        if not is_fp2 and _GLV_ENABLED:
-            # GLV (tpu/glv.py): each 255-bit scalar splits into two
-            # nonnegative <= 128-bit halves on (P, phi(P)) — the Horner
-            # schedule's doubling chain halves (52 -> 27 windows) for the
-            # same add count. G1 only (beta lives in Fp).
-            #
-            # PRECONDITION: points must lie in the r-order subgroup
-            # (phi(P) = lambda*P holds only there; E(Fp) has cofactor
-            # ~2^125). Every point that crosses the wire boundary is
-            # subgroup-checked at deserialization (ops/serialize.py
-            # g1_from_bytes/_from_compressed raise on non-r-torsion
-            # input), so all protocol callers satisfy this; callers
-            # feeding raw curve points from elsewhere must check
-            # g1.in_subgroup first or set COCONUT_GLV=0.
-            from . import glv
+        with otrace.span("encode"):
+            B = len(points_batch)
+            k = len(points_batch[0])
+            if any(len(row) != k for row in points_batch):
+                raise ValueError("ragged distinct-MSM batch")
+            if not is_fp2 and _GLV_ENABLED:
+                # GLV (tpu/glv.py): each 255-bit scalar splits into two
+                # nonnegative <= 128-bit halves on (P, phi(P)) — the Horner
+                # schedule's doubling chain halves (52 -> 27 windows) for the
+                # same add count. G1 only (beta lives in Fp).
+                #
+                # PRECONDITION: points must lie in the r-order subgroup
+                # (phi(P) = lambda*P holds only there; E(Fp) has cofactor
+                # ~2^125). Every point that crosses the wire boundary is
+                # subgroup-checked at deserialization (ops/serialize.py
+                # g1_from_bytes/_from_compressed raise on non-r-torsion
+                # input), so all protocol callers satisfy this; callers
+                # feeding raw curve points from elsewhere must check
+                # g1.in_subgroup first or set COCONUT_GLV=0.
+                from . import glv
 
-            points_batch = [
-                [q for p in row for q in (p, glv.phi(p))]
-                for row in points_batch
-            ]
-            scalars_batch = [
-                [h for s in row for h in glv.decompose(s)]
-                for row in scalars_batch
-            ]
-            k *= 2
-            bits = glv.HALF_BITS
-        else:
-            bits = 255
-        nwin = -(-bits // window) + 1  # 27 / 52 at the 5-bit default
-        flat_pts = [p for row in points_batch for p in row]
-        if is_fp2:
-            (x, y), inf = self._encode_g2_points(flat_pts)
-        else:
-            (x, y), inf = self._encode_g1_points(flat_pts)
-        reshape = lambda t: t.reshape((B, k) + t.shape[1:])
-        x, y = jax.tree_util.tree_map(reshape, (x, y))
-        inf = inf.reshape(B, k)
-        mag, sgn = _signed_digits(scalars_batch, nwin=nwin, window=window)
-        return x, y, inf, mag, sgn
+                points_batch = [
+                    [q for p in row for q in (p, glv.phi(p))]
+                    for row in points_batch
+                ]
+                scalars_batch = [
+                    [h for s in row for h in glv.decompose(s)]
+                    for row in scalars_batch
+                ]
+                k *= 2
+                bits = glv.HALF_BITS
+            else:
+                bits = 255
+            nwin = -(-bits // window) + 1  # 27 / 52 at the 5-bit default
+            flat_pts = [p for row in points_batch for p in row]
+            if is_fp2:
+                (x, y), inf = self._encode_g2_points(flat_pts)
+            else:
+                (x, y), inf = self._encode_g1_points(flat_pts)
+            reshape = lambda t: t.reshape((B, k) + t.shape[1:])
+            x, y = jax.tree_util.tree_map(reshape, (x, y))
+            inf = inf.reshape(B, k)
+            mag, sgn = _signed_digits(scalars_batch, nwin=nwin, window=window)
+            return x, y, inf, mag, sgn
 
     @staticmethod
     def _distinct_window(is_fp2, points_batch):
@@ -1289,10 +1291,13 @@ class JaxBackend(CurveBackend):
     def msm_distinct_wait(handle):
         """Block on a `_distinct` dispatch handle and decode to spec points."""
         ax, ay, ainf = handle
-        xs = tw.decode_batch(ax)
-        ys = tw.decode_batch(ay)
-        infs = np.asarray(ainf)
-        return [None if i else (xv, yv) for xv, yv, i in zip(xs, ys, infs)]
+        with otrace.span("decode"):
+            xs = tw.decode_batch(ax)
+            ys = tw.decode_batch(ay)
+            infs = np.asarray(ainf)
+            return [
+                None if i else (xv, yv) for xv, yv, i in zip(xs, ys, infs)
+            ]
 
     def msm_g1_distinct(self, points_batch, scalars_batch):
         return self.msm_distinct_wait(
@@ -1506,7 +1511,10 @@ class JaxBackend(CurveBackend):
         the current batch's device execution through this seam."""
         from .. import metrics
 
-        operands = self.encode_verify_batch(sigs, messages_list, vk, params)
+        with otrace.span("encode"):
+            operands = self.encode_verify_batch(
+                sigs, messages_list, vk, params
+            )
         bits = _fused_verify_kernel(params.ctx.name == "G1", *operands)
         metrics.count("verify_final_exps", len(sigs))
 
@@ -1526,7 +1534,10 @@ class JaxBackend(CurveBackend):
             return lambda: True
         if any(s.sigma_1 is None or s.sigma_2 is None for s in sigs):
             return lambda: False
-        operands = self.encode_grouped_batch(sigs, messages_list, vk, params)
+        with otrace.span("encode"):
+            operands = self.encode_grouped_batch(
+                sigs, messages_list, vk, params
+            )
         ok = _fused_verify_grouped_kernel(params.ctx.name == "G1", *operands)
         return lambda: bool(ok)
 
@@ -1601,7 +1612,10 @@ class JaxBackend(CurveBackend):
             # lane 0's total exponent r_0 * (1 + pad) != 0 mod R — sound,
             # and a pure function of the same transcript
             rs = list(rs) + [rs[0]] * pad
-        operands = self.encode_verify_batch(sigs, messages_list, vk, params)
+        with otrace.span("encode"):
+            operands = self.encode_verify_batch(
+                sigs, messages_list, vk, params
+            )
         wtables, mag, sgn, s1, s2n, gtx, gty, inf1, inf2 = operands
         rmag, rsgn = _combiner_digits(rs)
         ok = _fused_verify_combined_kernel(

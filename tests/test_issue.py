@@ -28,6 +28,7 @@ from coconut_tpu.issue import (
     HedgeScheduler,
     IssuanceService,
     QuorumTracker,
+    SigningAuthority,
 )
 from coconut_tpu.issue.quorum import Fanout
 from coconut_tpu.obs import trace as otrace
@@ -522,6 +523,56 @@ def test_watchdog_expires_hung_sign_quarantines_and_probation_revives():
         futs2 = _submit_batch(svc, 2)
         assert all(fut.result(timeout=5.0) for fut in futs2)
     assert metrics.get_count("issue_minted") == 4
+
+
+def test_disabled_mint_path_never_allocates_a_span(monkeypatch):
+    """Fan-out, five signs, the mint round and the release with the ring
+    and the profiler off: no Span and no profiler bridge is built."""
+
+    def boom(*a, **k):
+        raise AssertionError("span allocated while tracing is off")
+
+    monkeypatch.setattr(otrace, "Span", boom)
+    monkeypatch.setattr(otrace, "_Bridged", boom)
+    svc, clk, _ = _svc()
+    with svc:
+        futs = _submit_batch(svc)
+        creds = [f.result(10.0) for f in futs]
+    assert all(c.subset for c in creds)
+    assert metrics.get_count("issue_minted") == 2
+
+
+def test_join_survives_abandon_while_joining():
+    """abandon() drops the worker handle while a join() waits on it: the
+    join returns False (the stale worker is still mid-sign) instead of
+    raising, and a later join has nothing left to wait on."""
+    entered, gate = threading.Event(), threading.Event()
+
+    class Svc:
+        def _sign_fanout(self, auth, fanout, gen):
+            entered.set()
+            gate.wait(10.0)
+
+    auth = SigningAuthority(Svc(), _signers(1)[0], backend=StubSign())
+    auth.start()
+    auth.submit(SimpleNamespace(fid=1))
+    assert entered.wait(10.0)
+    out = {}
+
+    def joiner():
+        try:
+            out["joined"] = auth.join(timeout=2.0)
+        except Exception as e:  # the race this pins
+            out["error"] = e
+
+    t = threading.Thread(target=joiner)
+    t.start()
+    time.sleep(0.2)  # the joiner is inside Thread.join
+    assert auth.abandon() == []
+    t.join(10.0)
+    gate.set()
+    assert out == {"joined": False}
+    assert auth.join() is True and not auth.has_worker()
 
 
 def test_drain_fails_unreachable_fanouts_no_dangling_futures():

@@ -1,5 +1,6 @@
-"""Observability suite (ISSUE 6): request-scoped tracing, Perfetto
-export, the fault flight recorder, and the metrics percentile edge cases.
+"""Observability suite: request-scoped tracing, the bridge of stage spans
+into a jax.profiler trace, the fault flight recorder, and the metrics
+percentile edge cases.
 
 Economics mirror tests/test_serve.py: stub backends, injected clocks,
 zero real sleeps — span durations are proven by ADVANCING a fake clock.
@@ -7,10 +8,10 @@ Every test that enables tracing does so through the `_traced` fixture so
 the global tracer never leaks into other suites (tracing must stay a
 zero-cost no-op everywhere else)."""
 
-import json
+import glob
 import os
-import sys
 import threading
+import time
 from types import SimpleNamespace
 
 import pytest
@@ -25,11 +26,6 @@ from coconut_tpu.serve.batcher import Batcher, demux, fail_all
 from coconut_tpu.serve.queue import RequestQueue
 from coconut_tpu.serve.service import CredentialService
 from coconut_tpu.stream import verify_stream
-
-sys.path.insert(
-    0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "probes")
-)
-import probe_trace  # noqa: E402  (the CI validator doubles as a test helper)
 
 pytestmark = pytest.mark.obs
 
@@ -103,9 +99,17 @@ def test_disabled_path_never_allocates_a_span(monkeypatch):
     def boom(*a, **k):
         raise AssertionError("Span allocated while tracing disabled")
 
+    import jax.profiler
+
     monkeypatch.setattr(otrace, "Span", boom)
+    # the profiler bridge neither wraps nor annotates while no session
+    # collects
+    monkeypatch.setattr(otrace, "_Bridged", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
     with otrace.span("a"):
         otrace.event("e", k=1)
+    with otrace.span("b", ns="issue", fanout=1) as s:
+        assert s is otrace.NOOP
     otrace.start_span("b", root=True)
     otrace.end_span(otrace.NOOP)
     with otrace.use(otrace.NOOP):
@@ -127,6 +131,22 @@ def test_disabled_pool_dispatch_path_never_allocates_a_span(monkeypatch):
     with svc:
         futs = [svc.submit(_cred(), [0]) for _ in range(6)]
         assert all(f.result(10.0) for f in futs)
+
+
+def test_disabled_stream_path_never_allocates_a_span(monkeypatch):
+    """The offline verify stream (prefetch worker, dispatch, device,
+    checkpoint) with the ring and the profiler off: no Span, no bridge."""
+
+    def boom(*a, **k):
+        raise AssertionError("span allocated while tracing is off")
+
+    monkeypatch.setattr(otrace, "Span", boom)
+    monkeypatch.setattr(otrace, "_Bridged", boom)
+    state = verify_stream(
+        lambda i: ([_cred() for _ in range(4)], [[0]] * 4),
+        3, None, None, StubPerCred(),
+    )
+    assert state.verified == 12
 
 
 def test_env_flag_parse():
@@ -269,44 +289,6 @@ def test_reenable_replaces_tracer(clock):
 # --- export ----------------------------------------------------------------
 
 
-def test_chrome_export_structure_and_validation(tmp_path, _traced, clock):
-    with otrace.span("request") as r:
-        clock.advance(0.001)
-        with otrace.span("queue_wait"):
-            clock.advance(0.002)
-            otrace.event("retry", attempt=1)
-        with otrace.span("dispatch"):
-            clock.advance(0.003)
-        clock.advance(0.001)
-    path = str(tmp_path / "trace.json")
-    n = oexport.export_chrome(path)
-    doc = json.load(open(path))
-    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    instants = [e for e in doc["traceEvents"] if e["ph"] == "i"]
-    assert n == len(doc["traceEvents"]) == 4 and len(xs) == 3
-    by_name = {e["name"]: e for e in xs}
-    # microsecond denomination, exact on the fake clock
-    assert by_name["queue_wait"]["dur"] == pytest.approx(2000.0)
-    assert by_name["request"]["dur"] == pytest.approx(7000.0)
-    assert by_name["request"]["args"]["span_id"] == r.span_id
-    assert by_name["queue_wait"]["args"]["parent_id"] == r.span_id
-    assert instants[0]["name"] == "queue_wait.retry"
-    assert instants[0]["s"] == "t"
-    ts = [e["ts"] for e in doc["traceEvents"]]
-    assert ts == sorted(ts)
-    stats = probe_trace.validate(path)
-    assert stats["spans"] == 3 and stats["nested"] == 2
-
-
-def test_chrome_export_skips_live_spans(tmp_path, _traced):
-    otrace.start_span("live", root=True)
-    otrace.start_span("done", root=True).end()
-    path = str(tmp_path / "t.json")
-    oexport.write_chrome(_traced.tail() + _traced.live_snapshot(), path)
-    names = [e["name"] for e in json.load(open(path))["traceEvents"]]
-    assert names == ["done"]
-
-
 def test_jsonl_export_roundtrip(tmp_path, _traced, clock):
     with otrace.span("a", k="v"):
         clock.advance(1.0)
@@ -317,44 +299,6 @@ def test_jsonl_export_roundtrip(tmp_path, _traced, clock):
     assert rec["name"] == "a" and rec["dur"] == 1.0
     assert rec["attrs"] == {"k": "v"}
     assert rec["events"] == [{"ts": 1.0, "name": "e", "n": 1}]
-
-
-def test_probe_rejects_non_monotonic_and_escaping_children(tmp_path):
-    bad = {
-        "traceEvents": [
-            {"name": "a", "ph": "X", "ts": 10.0, "dur": 1.0, "pid": 1, "tid": 1},
-            {"name": "b", "ph": "X", "ts": 5.0, "dur": 1.0, "pid": 1, "tid": 1},
-        ]
-    }
-    p = str(tmp_path / "bad.json")
-    json.dump(bad, open(p, "w"))
-    with pytest.raises(AssertionError, match="monotonic"):
-        probe_trace.validate(p)
-    escape = {
-        "traceEvents": [
-            {
-                "name": "parent",
-                "ph": "X",
-                "ts": 0.0,
-                "dur": 5.0,
-                "pid": 1,
-                "tid": 1,
-                "args": {"span_id": 1, "parent_id": None},
-            },
-            {
-                "name": "child",
-                "ph": "X",
-                "ts": 4.0,
-                "dur": 50.0,
-                "pid": 1,
-                "tid": 1,
-                "args": {"span_id": 2, "parent_id": 1},
-            },
-        ]
-    }
-    json.dump(escape, open(p, "w"))
-    with pytest.raises(AssertionError, match="escapes parent"):
-        probe_trace.validate(p)
 
 
 # --- serve-path instrumentation --------------------------------------------
@@ -480,20 +424,144 @@ def test_serve_request_span_tree_retry_and_bisection(_traced, clock, tmp_path):
     }
 
 
-def test_threaded_serve_smoke_produces_valid_chrome_trace(tmp_path):
-    """Real supervisor thread + real clock: spans land, export validates,
-    loadgen-style stage breakdown shows up in metrics.snapshot()."""
+def test_threaded_serve_smoke_lands_bridged_spans_in_profiler_trace(tmp_path):
+    """Real supervisor thread + real clock under a CPU profiler session:
+    the serve program's stage spans land in the trace as coconut/serve.*
+    annotations, the ring still gets its stages, and the per-request
+    roots are not bridged."""
     otrace.enable(ring=256)
     svc = CredentialService(StubPerCred(), None, None, max_batch=2)
-    with svc:
-        futs = [svc.submit(_cred(), [0]) for _ in range(4)]
-        assert all(f.result(10.0) for f in futs)
-    path = str(tmp_path / "serve_trace.json")
-    assert oexport.export_chrome(path) > 0
-    probe_trace.validate(path)
+    names = {e["name"] for e in _profiled(tmp_path, lambda: _serve(svc))}
+    assert {"coconut/serve.coalesce", "coconut/serve.dispatch",
+            "coconut/serve.device", "coconut/serve.demux"} <= names
+    assert not {"coconut/request", "coconut/queue_wait", "coconut/batch",
+                "coconut/serve.request", "coconut/serve.batch"} & names
     stages = metrics.snapshot()["trace_stages"]
     for stage in ("request", "queue_wait", "batch", "dispatch", "device"):
         assert stages[stage]["count"] > 0, stage
+
+
+def _serve(svc):
+    with svc:
+        futs = [svc.submit(_cred(), [0]) for _ in range(4)]
+        assert all(f.result(10.0) for f in futs)
+
+
+# --- profiler bridge -------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run body() under a CPU jax.profiler session; return the trace's
+    "coconut/" host events as plain dicts."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from benchmark import trace as btrace
+
+    jax.profiler.start_trace(
+        str(tmp_path), profiler_options=btrace.profiler_options()
+    )
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "**", "*.xplane.pb"), recursive=True
+    )
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(otrace.PROFILER_PREFIX):
+                    out.append({
+                        "name": ev.name,
+                        "dur_s": ev.duration_ns / 1e9,
+                        "stats": dict(ev.stats),
+                    })
+    return out
+
+
+def test_bridge_puts_stage_spans_of_two_threads_in_the_profiler_trace(
+    tmp_path,
+):
+    tracer = otrace.enable(ring=256)  # real clock: durations compared
+
+    def sign():
+        with otrace.span("sign", ns="issue", fanout=7, authority="2"):
+            with otrace.span("encode"):  # shared code: inherits issue
+                time.sleep(0.02)
+
+    def body():
+        t = threading.Thread(target=sign)
+        t.start()
+        with otrace.span("dispatch", ns="stream"):
+            with otrace.span("encode"):
+                time.sleep(0.03)
+        t.join()
+        root = otrace.start_span("stream_batch", root=True)
+        threading.Thread(target=root.end).start()  # cross-thread: ring only
+
+    events = _profiled(tmp_path, body)
+    by_name = {e["name"]: e for e in events}
+    assert set(by_name) == {
+        "coconut/issue.sign", "coconut/issue.encode",
+        "coconut/stream.dispatch", "coconut/stream.encode",
+    }
+    assert by_name["coconut/issue.sign"]["stats"]["fanout"] == 7
+    ring = {}
+    for s in tracer.tail():
+        ring.setdefault(s.name, []).append(s.dur)
+    for label, want in [("sign", "coconut/issue.sign"),
+                        ("dispatch", "coconut/stream.dispatch")]:
+        (dur,) = ring[label]
+        assert abs(by_name[want]["dur_s"] - dur) < 1e-3, label
+    enc = sorted(ring["encode"])
+    got = sorted([by_name["coconut/issue.encode"]["dur_s"],
+                  by_name["coconut/stream.encode"]["dur_s"]])
+    assert all(abs(a - b) < 1e-3 for a, b in zip(enc, got))
+    hists = metrics.snapshot()["histograms"]
+    for h in ("bridge_issue_sign_s", "bridge_issue_encode_s",
+              "bridge_stream_dispatch_s", "bridge_stream_encode_s"):
+        assert hists[h]["count"] == 1, h
+    assert "stream_batch" in ring
+
+
+def test_bridge_is_silent_without_a_profiler_session(tmp_path):
+    tracer = otrace.enable(ring=16)
+    with otrace.span("dispatch", ns="stream") as s:
+        assert s in tracer.live_snapshot()
+    assert not isinstance(otrace.span("x"), otrace._Bridged)
+    assert "histograms" not in metrics.snapshot()
+    # nothing is emitted outside the session either
+    with otrace.span("before", ns="serve"):
+        pass
+    events = _profiled(tmp_path, lambda: None)
+    assert events == []
+
+
+def test_batcher_opens_backpressure_then_batch_wait(tmp_path):
+    """A queued request held by the ready gate reads "backpressure"; once
+    the gate opens it waits for its deadline under "batch_wait"; the
+    flush closes it."""
+    tracer = otrace.enable(ring=64)
+    q = RequestQueue(max_depth=4)
+    batcher = Batcher(q, max_batch=2, metric_ns="issue")
+
+    def body():
+        q.submit(_cred(), [0], max_wait_ms=150.0)
+        opens = time.monotonic() + 0.05
+        batch = batcher.next_batch(
+            block=True, ready=lambda: time.monotonic() > opens
+        )
+        assert len(batch) == 1
+
+    events = _profiled(tmp_path, body)
+    waits = [s for s in tracer.tail() if s.name != "queue_wait"]
+    assert [s.name for s in waits] == ["backpressure", "batch_wait"]
+    assert waits[0].dur > 0.04 and waits[1].dur > 0.05
+    assert {e["name"] for e in events} == {
+        "coconut/issue.backpressure", "coconut/issue.batch_wait"
+    }
 
 
 # --- stream-path instrumentation -------------------------------------------
