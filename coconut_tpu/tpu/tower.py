@@ -315,8 +315,8 @@ def fp12_cyclo_sq(a):
     stacked contraction = 30 base lanes, vs fp12_sq's 36 — and unlike
     fp12_sq the additive tail reuses the INPUT components, so each input
     component is compressed (one Montgomery mul by 1) to keep the lazy
-    value/limb class bounded across unbounded squaring chains (the scan in
-    pairing._pow_x_abs runs up to 31 consecutive squarings with no
+    value/limb class bounded across unbounded squaring chains (the loops in
+    pairing._pow_x_abs run up to 32 consecutive squarings with no
     intervening normalizing multiply):
       output limb weight <= 3*(3*132) + 2*132 = 1452 << L_LAZY = 2^17,
       output |value| <= 3*2p + 2*0.66p < 8p << V_LAZY = 1024p,

@@ -861,6 +861,73 @@ class TestCycloSq:
         assert d8[0] == w
 
 
+class TestPowX:
+    """pairing._pow_x_abs's segment schedule (squaring loops between the
+    static set bits of |BLS_X|, a multiply only at each of the 5) vs the
+    spec; the chain crosses the 32- and 16-squaring runs."""
+
+    def test_pow_x_abs_matches_spec(self):
+        import jax
+        from coconut_tpu.ops import fields as F
+        from coconut_tpu.ops.pairing import pairing
+        from coconut_tpu.tpu import pairing as tpr, tower as tw
+
+        p1 = g1.mul(G1_GEN, rng.randrange(1, R))
+        q2 = g2.mul(G2_GEN, rng.randrange(1, R))
+        gts = [pairing(p1, q2), pairing(None, q2)]  # the second is 1
+        got = tw.decode_batch(jax.jit(tpr._pow_x_abs)(tw.encode_batch(gts)))
+        assert got == [F.fp12_pow(gt, -F.BLS_X) for gt in gts]
+
+    def test_final_exp_matches_spec(self):
+        import jax
+        from coconut_tpu.ops import pairing as spr
+        from coconut_tpu.tpu import pairing as tpr, tower as tw
+
+        fs = [
+            spr.miller_loop_projective(
+                g1.mul(G1_GEN, rng.randrange(1, R)),
+                g2.mul(G2_GEN, rng.randrange(1, R)),
+            )
+            for _ in range(2)
+        ]
+        got = tw.decode_batch(jax.jit(tpr.final_exp)(tw.encode_batch(fs)))
+        assert got == [spr.final_exp(f) for f in fs]
+
+    def test_schedule_has_no_select_and_five_multiplies(self, monkeypatch):
+        """One scan step per set bit (its squarings, then one multiply),
+        then the trailing squarings: 63 squarings, 5 multiplies, and no
+        multiply computed only to be selected away."""
+        import jax
+        from coconut_tpu.ops.fields import BLS_X, FP12_ONE
+        from coconut_tpu.tpu import pairing as tpr, tower as tw
+
+        muls = []
+        fp12_mul = tw.fp12_mul
+
+        def counted(a, b):
+            muls.append(1)
+            return fp12_mul(a, b)
+
+        monkeypatch.setattr(tw, "fp12_mul", counted)
+        jaxpr = jax.make_jaxpr(tpr._pow_x_abs)(tw.encode_batch([FP12_ONE]))
+
+        def prims(jx):
+            for eqn in jx.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from prims(sub)
+
+        assert tpr._SEG_SQUARES == [1, 2, 3, 9, 32]
+        steps = (-BLS_X).bit_length() - 1  # every bit after the leading one
+        assert sum(tpr._SEG_SQUARES) + tpr._TRAILING == steps == 63
+        scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+        assert [e.params["length"] for e in scans] == [5, tpr._TRAILING]
+        set_bits = list(prims(scans[0].params["jaxpr"].jaxpr))
+        assert set_bits.count("while") == 1  # the squarings before the bit
+        assert "select_n" not in set(prims(jaxpr.jaxpr))
+        assert len(muls) == 1  # traced once, run once per set bit
+
+
 class TestGroupedMsms:
     """_grouped_msms (signed 6-bit schedule) vs the spec MSM — the whole
     per-credential arithmetic of the headline grouped verify."""
